@@ -1,7 +1,8 @@
 """Batched serving example: prefill + decode with KV cache and sampling.
 
-Run: PYTHONPATH=src python examples/serve_lm.py [--arch yi-6b]
-(reduced config on CPU; the same serve loop drives the decode dry-run cells)
+Run: PYTHONPATH=src python examples/serve_lm.py [--arch yi-6b] [--full]
+(the reduced config by default; ``--full`` serves the published widths,
+which for yi-6b means 12.1 GB of bf16 weights: one TPU v5e chip)
 """
 
 import argparse
@@ -21,6 +22,8 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.8)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="the published widths (default: the reduced config)")
     args = ap.parse_args()
 
     rng = np.random.default_rng(0)
@@ -28,11 +31,12 @@ def main() -> None:
         list(rng.integers(1, 200, size=rng.integers(4, args.prompt_len + 1)))
         for _ in range(args.batch)
     ]
-    print(f"serving {args.batch} requests (ragged prompts) on {args.arch} [reduced]")
+    width = "reduced" if args.smoke else "full width"
+    print(f"serving {args.batch} requests (ragged prompts) on {args.arch} [{width}]")
     res = serve_batch(
         args.arch,
         prompts,
-        smoke=True,
+        smoke=args.smoke,
         max_new_tokens=args.max_new,
         cache_len=64,
         temperature=args.temperature,

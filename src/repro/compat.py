@@ -1,18 +1,10 @@
-"""Version-compat shims for JAX API drift, resolved once in one place.
+"""The one place that names JAX APIs which moved between versions.
 
-The repo is written against the newest JAX surface; older installed
-versions spell the same features differently.  Policy (see ROADMAP.md
-"Open items"): every cross-version API difference is absorbed *here* —
-kernel and model code imports from ``repro.compat`` and never probes
-``jax.*`` attributes itself, so the next drift is a one-file fix.
-
-Currently shimmed:
-
-* ``tpu_compiler_params(**kw)`` — ``jax.experimental.pallas.tpu`` renamed
-  ``TPUCompilerParams`` to ``CompilerParams``; resolve whichever exists.
-* ``shard_map(...)`` — ``jax.shard_map`` (new spelling, ``check_vma=``)
-  vs ``jax.experimental.shard_map.shard_map`` (old spelling,
-  ``check_rep=``).  The wrapper accepts either keyword and translates.
+The repo targets JAX 0.9: ``jax.shard_map`` (``check_vma=``),
+``jax.lax.axis_size`` and ``jax.experimental.pallas.tpu.CompilerParams``.
+Kernel and model code imports these from ``repro.compat`` and never probes
+``jax.*`` attributes itself (the ``no-jax-probe-outside-compat`` lint
+rule), so the next API move is a one-file fix.
 """
 
 from __future__ import annotations
@@ -27,75 +19,13 @@ __all__ = [
     "tpu_compiler_params",
 ]
 
-
-def axis_size(axis_name) -> Any:
-    """``jax.lax.axis_size`` on new JAX; ``psum(1, axis)`` on old."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# --- Pallas TPU CompilerParams -------------------------------------------
-
-# Resolved lazily: repro.compat is imported by non-Pallas consumers
-# (models, launch) for shard_map/axis_size, and an eager pallas.tpu probe
-# would turn a Pallas-only drift into a whole-suite import failure.
-_COMPILER_PARAMS_CLS = None
-
-
-def _resolve_compiler_params_cls():
-    global _COMPILER_PARAMS_CLS
-    if _COMPILER_PARAMS_CLS is None:
-        from jax.experimental.pallas import tpu as pltpu
-
-        for name in ("CompilerParams", "TPUCompilerParams"):
-            cls = getattr(pltpu, name, None)
-            if cls is not None:
-                _COMPILER_PARAMS_CLS = cls
-                break
-        else:
-            raise AttributeError(
-                "jax.experimental.pallas.tpu exposes neither CompilerParams "
-                "nor TPUCompilerParams; unsupported JAX version"
-            )
-    return _COMPILER_PARAMS_CLS
+axis_size = jax.lax.axis_size
+shard_map = jax.shard_map
 
 
 def tpu_compiler_params(**kwargs: Any):
-    """Build the Pallas-TPU compiler-params object under either name."""
-    return _resolve_compiler_params_cls()(**kwargs)
+    """``pltpu.CompilerParams(**kwargs)``.  Imported on first use, so the
+    consumers of ``shard_map``/``axis_size`` never load Pallas."""
+    from jax.experimental.pallas import tpu as pltpu
 
-
-# --- shard_map ------------------------------------------------------------
-
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _OLD_SHARD_MAP
-else:
-    _OLD_SHARD_MAP = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs: Any):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old.
-
-    Accepts both replication-check spellings (``check_vma=`` new,
-    ``check_rep=`` old) and forwards whichever the resolved function
-    understands.
-    """
-    check = None
-    if "check_vma" in kwargs:
-        check = kwargs.pop("check_vma")
-    if "check_rep" in kwargs:
-        check = kwargs.pop("check_rep")
-    if _NEW_SHARD_MAP is not None:
-        if check is not None:
-            kwargs["check_vma"] = check
-        return _NEW_SHARD_MAP(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    if check is not None:
-        kwargs["check_rep"] = check
-    return _OLD_SHARD_MAP(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
+    return pltpu.CompilerParams(**kwargs)

@@ -239,7 +239,7 @@ def _tp_shard_map_matmul(x, w, mode: str, out_dtype, plan):
             mesh=mesh,
             in_specs=(P(dp, None, "model"), P("model", None)),
             out_specs=P(dp, None, None),
-            check_rep=False,
+            check_vma=False,
         )(x, w)
     # col: output dim sharded; bwd dX psum happens in out_dtype
 
@@ -254,7 +254,7 @@ def _tp_shard_map_matmul(x, w, mode: str, out_dtype, plan):
         mesh=mesh,
         in_specs=(P(dp, None, None), P(None, "model")),
         out_specs=P(dp, None, "model"),
-        check_rep=False,
+        check_vma=False,
     )(x, w)
 
 

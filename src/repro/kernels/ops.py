@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas device kernels.
 
 This is the device half of the BLAS seam: ``repro.core.blas`` routes here
-when the offload policy selects the Pallas backend.  On this CPU container
-the kernels execute with ``interpret=True``; on a real TPU the same calls
-lower through Mosaic.  The `interpret` flag is plumbed, never hard-coded.
+when the offload policy selects the Pallas backend.  On a TPU the calls
+lower through Mosaic; the CPU tests run the same calls with
+``interpret=True``.  The `interpret` flag is plumbed, never hard-coded.
 """
 
 from __future__ import annotations

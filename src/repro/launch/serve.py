@@ -23,6 +23,7 @@ sum.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional
@@ -34,34 +35,39 @@ import numpy as np
 from repro.configs import get_arch
 from repro.core import cost_model as cm
 from repro.core.hero import DeviceHandle, engine
-from repro.launch import costing
-from repro.launch.steps import make_serve_step
+from repro.launch import costing, use_compile_cache
+from repro.launch.steps import init_params, make_serve_step
 from repro.models import build_model
+from repro.sharding import cache_pspecs, named
 
 
 @dataclasses.dataclass
 class ServeResult:
     tokens: np.ndarray          # (B, max_new)
+    logits: np.ndarray          # (B, max_new, V) float32: each token's source
     prefill_s: float
     decode_s: float
     tokens_per_s: float
+    # Seconds spent compiling the step, timed apart from prefill.  0.0 where
+    # the step compiles inside the first prefill call (serve_cluster).
+    compile_s: float = 0.0
 
 
 def _run_prefill(serve_step, params, cache, prompts: List[List[int]]):
     """Prefill token-by-token through the decode path (correct for rolling
     caches and hybrid state; a fused prefill kernel is a perf option)."""
-    bsz = len(prompts)
     max_prompt = max(len(p) for p in prompts)
-    t0 = time.time()
-    tok = np.zeros((bsz, 1), np.int32)
+    t0 = time.perf_counter()
     logits = None
     for t in range(max_prompt):
-        for b, p in enumerate(prompts):
-            tok[b, 0] = p[t] if t < len(p) else 0
+        # A fresh host buffer every step: the step may still be reading the
+        # last one (on the CPU, jnp.asarray can alias numpy memory).
+        tok = np.array([[p[t] if t < len(p) else 0] for p in prompts], np.int32)
         logits, cache = serve_step(
             params, cache, jnp.asarray(tok), jnp.int32(t)
         )
-    return logits, cache, time.time() - t0
+    jax.block_until_ready(logits)
+    return logits, cache, time.perf_counter() - t0
 
 
 def _run_decode(
@@ -72,9 +78,11 @@ def _run_decode(
     bsz = logits.shape[0]
     rng = np.random.default_rng(seed)
     out = np.zeros((bsz, max_new_tokens), np.int32)
-    t0 = time.time()
+    seen = []
+    t0 = time.perf_counter()
     for i in range(max_new_tokens):
         lf = np.asarray(logits, np.float32)
+        seen.append(lf)
         if temperature > 0:
             p = np.exp((lf - lf.max(-1, keepdims=True)) / temperature)
             p /= p.sum(-1, keepdims=True)
@@ -87,7 +95,8 @@ def _run_decode(
         logits, cache = serve_step(
             params, cache, jnp.asarray(nxt[:, None]), jnp.int32(start_pos + i)
         )
-    return out, cache, time.time() - t0
+    jax.block_until_ready(cache)
+    return out, np.stack(seen, axis=1), cache, time.perf_counter() - t0
 
 
 def serve_batch(
@@ -100,7 +109,15 @@ def serve_batch(
     temperature: float = 0.0,
     seed: int = 0,
     params=None,
+    mesh=None,
 ) -> ServeResult:
+    """Prefill and decode one batch of prompts.
+
+    ``params`` defaults to weights built from ``seed`` (:func:`init_params`).
+    With a ``mesh`` (axes ``data`` and ``model``) the weights and the cache
+    are sharded by ``param_pspecs``/``cache_pspecs``, and the step traces
+    under the mesh, so the matmuls take their tensor-parallel plans.
+    """
     cfg = get_arch(arch)
     if smoke:
         cfg = cfg.reduced()
@@ -110,23 +127,46 @@ def serve_batch(
         raise ValueError("encoder-only arch has no decode step")
     model = build_model(cfg)
     if params is None:
-        params = model.init_params(jax.random.PRNGKey(seed))
+        params = init_params(model, seed, mesh)
 
     bsz = len(prompts)
     max_prompt = max(len(p) for p in prompts)
-    cache = model.init_decode_cache(bsz, cache_len)
-    serve_step = jax.jit(make_serve_step(model), donate_argnums=(1,))
+
+    def new_cache():
+        return model.init_decode_cache(bsz, cache_len)
+
+    step_kw = {}
+    if mesh is None:
+        cache = jax.jit(new_cache)()
+    else:
+        c_shard = named(mesh, cache_pspecs(jax.eval_shape(new_cache), mesh))
+        cache = jax.jit(new_cache, out_shardings=c_shard)()
+        p_shard = jax.tree_util.tree_map(lambda x: x.sharding, params)
+        rep = named(mesh, jax.sharding.PartitionSpec())
+        step_kw = dict(in_shardings=(p_shard, c_shard, rep, rep),
+                       out_shardings=(rep, c_shard))
+    t0 = time.perf_counter()
+    with contextlib.nullcontext() if mesh is None else mesh:
+        serve_step = jax.jit(
+            make_serve_step(model), donate_argnums=(1,), **step_kw
+        ).lower(
+            params, cache, jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+        ).compile()
+    compile_s = time.perf_counter() - t0
 
     logits, cache, prefill_s = _run_prefill(serve_step, params, cache, prompts)
-    out, cache, decode_s = _run_decode(
+    out, seen, cache, decode_s = _run_decode(
         serve_step, params, cache, logits, start_pos=max_prompt,
         max_new_tokens=max_new_tokens, temperature=temperature, seed=seed,
     )
     return ServeResult(
         tokens=out,
+        logits=seen,
         prefill_s=prefill_s,
         decode_s=decode_s,
         tokens_per_s=bsz * max_new_tokens / max(decode_s, 1e-9),
+        compile_s=compile_s,
     )
 
 
@@ -225,7 +265,7 @@ def serve_cluster(
     cluster = engine()
     # one set of weights serves every batch (and one jit cache warms up)
     model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(seed))
+    params = init_params(model, seed)
     serve_step = jax.jit(make_serve_step(model), donate_argnums=(1,))
 
     per_device_s: Dict[int, float] = {}
@@ -293,7 +333,7 @@ def serve_cluster(
                 move_s = cluster.migrate_handle(handle, d_dev).offload_s
                 d2d_s += move_s
             with cluster.pin_device(d_dev):
-                out, cache, decode_s = _run_decode(
+                out, seen, cache, decode_s = _run_decode(
                     serve_step, params, cache, logits, start_pos=max_prompt,
                     max_new_tokens=max_new_tokens, temperature=temperature,
                     seed=seed,
@@ -308,6 +348,7 @@ def serve_cluster(
             per_device_s[d_dev] = per_device_s.get(d_dev, 0.0) + lane_s
             results.append(ServeResult(
                 tokens=out,
+                logits=seen,
                 prefill_s=prefill_s,
                 decode_s=decode_s,
                 tokens_per_s=(
@@ -339,6 +380,8 @@ def serve_cluster(
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="the published widths (default: the reduced config)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=16)
@@ -365,6 +408,7 @@ def main() -> None:
                     help="trace duration for --stream (modeled seconds)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     if args.stream:
         from repro.launch.streaming import (
             StreamConfig, bursty_trace, serve_stream,
@@ -397,7 +441,8 @@ def main() -> None:
         with offload_policy(mode=args.policy_mode, num_devices=args.devices,
                             scheduler=args.scheduler):
             res = serve_cluster(
-                args.arch, batches, max_new_tokens=args.max_new,
+                args.arch, batches, smoke=args.smoke,
+                max_new_tokens=args.max_new,
                 temperature=args.temperature,
                 pin_caches=not args.no_pin_caches,
                 forward_mode=args.forward_mode,
@@ -411,11 +456,11 @@ def main() -> None:
         return
     prompts = [list(rng.integers(1, 200, size=args.prompt_len)) for _ in range(args.batch)]
     res = serve_batch(
-        args.arch, prompts, max_new_tokens=args.max_new,
-        temperature=args.temperature,
+        args.arch, prompts, smoke=args.smoke, max_new_tokens=args.max_new,
+        temperature=args.temperature, seed=args.seed,
     )
-    print(f"prefill {res.prefill_s:.2f}s decode {res.decode_s:.2f}s "
-          f"{res.tokens_per_s:.1f} tok/s")
+    print(f"compile {res.compile_s:.2f}s prefill {res.prefill_s:.2f}s "
+          f"decode {res.decode_s:.2f}s {res.tokens_per_s:.1f} tok/s")
     print(res.tokens)
 
 

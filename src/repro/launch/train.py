@@ -20,10 +20,15 @@ import numpy as np
 from repro.checkpoint import Checkpointer
 from repro.configs import get_arch
 from repro.data import SyntheticLM
+from repro.launch import use_compile_cache
 from repro.launch.mesh import make_local_mesh
-from repro.launch.steps import TrainOptions, init_train_state, make_train_step
+from repro.launch.steps import (
+    TrainOptions,
+    init_params,
+    init_train_state,
+    make_train_step,
+)
 from repro.models import build_model
-from repro.sharding import batch_pspecs, named, opt_pspecs, param_pspecs
 
 
 def train(
@@ -59,7 +64,7 @@ def train(
         compress_grads=compress_grads,
     )
 
-    params = model.init_params(jax.random.PRNGKey(0))
+    params = init_params(model, 0, mesh)
     opt_state, err = init_train_state(model, params, opts)
 
     ckpt = Checkpointer(Path(ckpt_dir))
@@ -69,7 +74,6 @@ def train(
         print(f"resumed from step {start_step}")
 
     data = SyntheticLM(cfg.vocab_size, seq_len, global_batch, seed=17)
-    p_shard = named(mesh, param_pspecs(jax.eval_shape(lambda: params), mesh))
     step_fn = jax.jit(
         make_train_step(model, opts), donate_argnums=(0, 1),
     )
@@ -104,6 +108,7 @@ def main() -> None:
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--no-resume", dest="resume", action="store_false")
     args = ap.parse_args()
+    use_compile_cache()
     train(
         args.arch,
         smoke=args.smoke,

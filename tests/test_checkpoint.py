@@ -112,7 +112,8 @@ def test_mesh_agnostic_restore_onto_sharding(tmp_path):
 
     t = {"w": jnp.arange(16.0).reshape(4, 4)}
     save_pytree(t, tmp_path / "c")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = {"w": NamedSharding(mesh, P("data", None))}
     got = restore_pytree(t, tmp_path / "c", shardings=sh)
     _assert_tree_equal(t, got)

@@ -13,12 +13,12 @@ SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import json
     import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.compat import shard_map
     from repro.sharding.collective_matmul import ring_ag_matmul
 
     N, B, S, D, F = 4, 2, 16, 8, 12
-    mesh = jax.make_mesh((N,), ("model",))
+    mesh = jax.make_mesh((N,), ("model",), axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (B, S, D))
     w = jax.random.normal(jax.random.PRNGKey(1), (D, F))
 

@@ -107,7 +107,8 @@ def test_straggler_needs_history():
 
 
 def test_elastic_replan_batch_split():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_arch("yi-6b").reduced()
     model = build_model(cfg)
     shapes = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0)))

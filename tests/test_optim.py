@@ -124,7 +124,8 @@ def test_compressed_psum_over_real_axis():
         from repro.compat import shard_map
         from repro.optim import compressed_psum, init_error_buffer
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 256))  # per-device rows
 
         def local(g_loc):

@@ -16,7 +16,8 @@ PIPE = textwrap.dedent(
     from repro.launch.pipeline import pipeline_apply
 
     S, D, B, M = 4, 16, 8, 4
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     params = {"w": jax.random.normal(k1, (S, D, D)) * 0.3,
               "b": jax.random.normal(k2, (S, D)) * 0.1}

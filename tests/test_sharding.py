@@ -109,7 +109,8 @@ MINI_DRYRUN = textwrap.dedent(
     from repro.sharding import batch_pspecs, named, opt_pspecs, param_pspecs
     from repro.roofline.hlo_parse import analyze_module
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = dataclasses.replace(
         get_arch("yi-6b").reduced(), num_layers=4, num_microbatches=2,
         d_model=128, d_ff=256, vocab_size=512, num_heads=4, num_kv_heads=2,
@@ -158,7 +159,8 @@ TP_NUMERICS = textwrap.dedent(
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 256),
              "labels": jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, 256)}
     ref, _ = m.forward(params, batch)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with mesh:
         tp, _ = jax.jit(m.forward)(params, batch)
         _ = jax.jit(jax.grad(lambda p: m.loss(p, batch)))(params)
@@ -190,6 +192,51 @@ TP_NUMERICS = textwrap.dedent(
                       "tp_mm_err": tp_mm_err}))
     """
 )
+
+
+TP_SERVE = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax, numpy as np
+    from repro.configs import get_arch
+    from repro.launch.serve import serve_batch
+    from repro.launch.steps import init_params
+    from repro.models import build_model
+
+    prompts = [[3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8]]
+    kw = dict(smoke=True, max_new_tokens=5, cache_len=16)
+    one = serve_batch("yi-6b", prompts, **kw)
+    mesh = jax.make_mesh((1, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    params = init_params(build_model(get_arch("yi-6b").reduced()), 0, mesh)
+    wq = params["stack"]["mixer"]["wq"]
+    four = serve_batch("yi-6b", prompts, params=params, mesh=mesh, **kw)
+    print(json.dumps({
+        "wq_shards": [s.data.shape[-1] for s in wq.addressable_shards],
+        "wq_cols": wq.shape[-1],
+        "same_tokens": bool((one.tokens == four.tokens).all()),
+        "logit_err": float(np.abs(one.logits - four.logits).max()),
+    }))
+    """
+)
+
+
+def test_serve_batch_on_model_mesh_matches_one_device():
+    """serve_batch with a (data=1, model=4) mesh: weights land sharded and
+    the tensor-parallel decode reproduces the one-device run."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run(
+        [sys.executable, "-c", TP_SERVE], env=env,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["wq_shards"] == [rec["wq_cols"] // 4] * 4, rec
+    assert rec["same_tokens"], rec
+    assert rec["logit_err"] < 1e-4, rec
 
 
 def test_tp_and_moe_shard_map_numerics():

@@ -47,6 +47,22 @@ def test_serve_batch_greedy():
     assert res.tokens_per_s > 0
 
 
+def test_serve_batch_logits_match_forward():
+    """Each token's logits from the prefill/decode loop equal the model's
+    own forward pass over the same tokens (float32 reduced config)."""
+    prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8, 2, 8, 1, 8]]
+    res = serve_batch("yi-6b", prompts, smoke=True, max_new_tokens=6,
+                      cache_len=32)
+    assert res.compile_s > 0
+    assert res.logits.shape == (2, 6, 256)
+    np.testing.assert_array_equal(res.logits.argmax(-1), res.tokens)
+    model = build_model(get_arch("yi-6b").reduced())
+    params = jax.jit(model.init_params)(jax.random.PRNGKey(0))
+    seq = np.concatenate([np.asarray(prompts), res.tokens[:, :-1]], axis=1)
+    ref, _ = model.forward(params, {"tokens": jnp.asarray(seq)})
+    np.testing.assert_allclose(res.logits, np.asarray(ref)[:, 7:], atol=1e-4)
+
+
 def test_serve_rejects_encoder():
     with pytest.raises(ValueError):
         serve_batch("hubert-xlarge", [[1, 2]], smoke=True)
