@@ -362,12 +362,6 @@ class VirtualDevice:
         self.enqueue(ticket)
         _flight.note_ticket(ticket)
         _metrics.counter("stream.tickets", kind=kind).inc()
-        if bd.copy_s > 0 and cost.staged_bytes > 0:
-            charged = cost.staged_bytes * (1.0 - float(resident_fraction))
-            chunks = bd.chunks if isinstance(bd, PipelinedBreakdown) else 1
-            if charged > 0:
-                _metrics.histogram("staging.leg_bytes").observe(
-                    charged / chunks, n=chunks)
         tr = _spans.current_tracer()
         if tr is not None:
             _trace_ticket(tr, ticket, bd)

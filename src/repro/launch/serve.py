@@ -25,8 +25,9 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
+import itertools
 import time
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,17 @@ class ServeResult:
     # Seconds spent compiling the step, timed apart from prefill.  0.0 where
     # the step compiles inside the first prefill call (serve_cluster).
     compile_s: float = 0.0
+    # The compiled step the batch ran on (None where no step is compiled
+    # apart, as in serve_cluster).  Its ``as_text()`` names each op of a
+    # profile and carries the op's ``jax.named_scope`` path (models.SCOPES).
+    step: Any = None
+
+
+# Host spans in the profiler's trace; with no profiler running, a span only
+# checks that none is.  Each ``serve.batch`` carries a process-wide call
+# number, which the spans nested in it share.
+_span = jax.profiler.TraceAnnotation
+_batch_ids = itertools.count()
 
 
 def _run_prefill(serve_step, params, cache, prompts: List[List[int]]):
@@ -59,14 +71,17 @@ def _run_prefill(serve_step, params, cache, prompts: List[List[int]]):
     max_prompt = max(len(p) for p in prompts)
     t0 = time.perf_counter()
     logits = None
-    for t in range(max_prompt):
-        # A fresh host buffer every step: the step may still be reading the
-        # last one (on the CPU, jnp.asarray can alias numpy memory).
-        tok = np.array([[p[t] if t < len(p) else 0] for p in prompts], np.int32)
-        logits, cache = serve_step(
-            params, cache, jnp.asarray(tok), jnp.int32(t)
-        )
-    jax.block_until_ready(logits)
+    with _span("serve.prefill", steps=max_prompt):
+        for t in range(max_prompt):
+            # A fresh host buffer every step: the step may still be reading
+            # the last one (on the CPU, jnp.asarray can alias numpy memory).
+            tok = np.array([[p[t] if t < len(p) else 0] for p in prompts],
+                           np.int32)
+            logits, cache = serve_step(
+                params, cache, jnp.asarray(tok), jnp.int32(t)
+            )
+        with _span("serve.prefill.wait"):
+            jax.block_until_ready(logits)
     return logits, cache, time.perf_counter() - t0
 
 
@@ -79,24 +94,36 @@ def _run_decode(
     rng = np.random.default_rng(seed)
     out = np.zeros((bsz, max_new_tokens), np.int32)
     seen = []
+    copied = logits.nbytes  # device bytes each token's copy reads
     t0 = time.perf_counter()
     for i in range(max_new_tokens):
-        lf = np.asarray(logits, np.float32)
+        # np.asarray would wait too: the wait is its own span so that the
+        # copy's span times the copy alone.
+        with _span("serve.decode.wait"):
+            jax.block_until_ready(logits)
+        with _span("serve.decode.copy", bytes=copied):
+            lf = np.asarray(logits, np.float32)
         seen.append(lf)
-        if temperature > 0:
-            p = np.exp((lf - lf.max(-1, keepdims=True)) / temperature)
-            p /= p.sum(-1, keepdims=True)
-            nxt = np.array(
-                [rng.choice(lf.shape[-1], p=p[b]) for b in range(bsz)], np.int32
+        with _span("serve.decode.pick"):
+            if temperature > 0:
+                p = np.exp((lf - lf.max(-1, keepdims=True)) / temperature)
+                p /= p.sum(-1, keepdims=True)
+                nxt = np.array(
+                    [rng.choice(lf.shape[-1], p=p[b]) for b in range(bsz)],
+                    np.int32,
+                )
+            else:
+                nxt = lf.argmax(-1).astype(np.int32)
+            out[:, i] = nxt
+        with _span("serve.decode.dispatch"):
+            logits, cache = serve_step(
+                params, cache, jnp.asarray(nxt[:, None]),
+                jnp.int32(start_pos + i),
             )
-        else:
-            nxt = lf.argmax(-1).astype(np.int32)
-        out[:, i] = nxt
-        logits, cache = serve_step(
-            params, cache, jnp.asarray(nxt[:, None]), jnp.int32(start_pos + i)
-        )
     jax.block_until_ready(cache)
-    return out, np.stack(seen, axis=1), cache, time.perf_counter() - t0
+    with _span("serve.decode.stack", bytes=sum(lf.nbytes for lf in seen)):
+        seen = np.stack(seen, axis=1)
+    return out, seen, cache, time.perf_counter() - t0
 
 
 def serve_batch(
@@ -117,6 +144,9 @@ def serve_batch(
     With a ``mesh`` (axes ``data`` and ``model``) the weights and the cache
     are sharded by ``param_pspecs``/``cache_pspecs``, and the step traces
     under the mesh, so the matmuls take their tensor-parallel plans.
+
+    Under a profiler (``jax.profiler.trace``) the call leaves host spans
+    named ``serve.*`` in its trace (README, "Tracing a serving run").
     """
     cfg = get_arch(arch)
     if smoke:
@@ -125,41 +155,49 @@ def serve_batch(
         raise ValueError("serving driver targets token-input archs")
     if cfg.is_encoder:
         raise ValueError("encoder-only arch has no decode step")
-    model = build_model(cfg)
-    if params is None:
-        params = init_params(model, seed, mesh)
-
     bsz = len(prompts)
     max_prompt = max(len(p) for p in prompts)
+    with _span("serve.batch", batch=next(_batch_ids), requests=bsz,
+               prompt_len=max_prompt, new_tokens=max_new_tokens,
+               cache_len=cache_len):
+        model = build_model(cfg)
+        if params is None:
+            params = init_params(model, seed, mesh)
 
-    def new_cache():
-        return model.init_decode_cache(bsz, cache_len)
+        def new_cache():
+            return model.init_decode_cache(bsz, cache_len)
 
-    step_kw = {}
-    if mesh is None:
-        cache = jax.jit(new_cache)()
-    else:
-        c_shard = named(mesh, cache_pspecs(jax.eval_shape(new_cache), mesh))
-        cache = jax.jit(new_cache, out_shardings=c_shard)()
-        p_shard = jax.tree_util.tree_map(lambda x: x.sharding, params)
-        rep = named(mesh, jax.sharding.PartitionSpec())
-        step_kw = dict(in_shardings=(p_shard, c_shard, rep, rep),
-                       out_shardings=(rep, c_shard))
-    t0 = time.perf_counter()
-    with contextlib.nullcontext() if mesh is None else mesh:
-        serve_step = jax.jit(
-            make_serve_step(model), donate_argnums=(1,), **step_kw
-        ).lower(
-            params, cache, jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32),
-        ).compile()
-    compile_s = time.perf_counter() - t0
+        step_kw = {}
+        with _span("serve.compile"):
+            with _span("serve.compile.cache"):
+                if mesh is None:
+                    cache = jax.jit(new_cache)()
+                else:
+                    c_shard = named(
+                        mesh, cache_pspecs(jax.eval_shape(new_cache), mesh))
+                    cache = jax.jit(new_cache, out_shardings=c_shard)()
+                    p_shard = jax.tree_util.tree_map(lambda x: x.sharding,
+                                                     params)
+                    rep = named(mesh, jax.sharding.PartitionSpec())
+                    step_kw = dict(in_shardings=(p_shard, c_shard, rep, rep),
+                                   out_shardings=(rep, c_shard))
+            t0 = time.perf_counter()
+            in_mesh = contextlib.nullcontext() if mesh is None else mesh
+            with _span("serve.compile.step"), in_mesh:
+                serve_step = jax.jit(
+                    make_serve_step(model), donate_argnums=(1,), **step_kw
+                ).lower(
+                    params, cache, jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32),
+                ).compile()
+            compile_s = time.perf_counter() - t0
 
-    logits, cache, prefill_s = _run_prefill(serve_step, params, cache, prompts)
-    out, seen, cache, decode_s = _run_decode(
-        serve_step, params, cache, logits, start_pos=max_prompt,
-        max_new_tokens=max_new_tokens, temperature=temperature, seed=seed,
-    )
+        logits, cache, prefill_s = _run_prefill(serve_step, params, cache,
+                                                prompts)
+        out, seen, cache, decode_s = _run_decode(
+            serve_step, params, cache, logits, start_pos=max_prompt,
+            max_new_tokens=max_new_tokens, temperature=temperature, seed=seed,
+        )
     return ServeResult(
         tokens=out,
         logits=seen,
@@ -167,6 +205,7 @@ def serve_batch(
         decode_s=decode_s,
         tokens_per_s=bsz * max_new_tokens / max(decode_s, 1e-9),
         compile_s=compile_s,
+        step=serve_step,
     )
 
 

@@ -13,6 +13,7 @@ from repro.core import blas
 from repro.core.blas import psum_cast_dtype  # noqa: F401
 
 __all__ = [
+    "SCOPES",
     "rms_norm",
     "layer_norm",
     "rope",
@@ -22,6 +23,13 @@ __all__ = [
     "init_dense",
     "init_norm",
 ]
+
+
+# The ``jax.named_scope`` names of a model's parts, the same in the train,
+# prefill and decode programs.  They reach each compiled op's
+# ``metadata={op_name=...}``, so a profile's ops map to a part by name.
+EMBED, NORM, ATTENTION, MLP, HEAD = SCOPES = (
+    "embed", "norm", "attention", "mlp", "head")
 
 
 # ---------------------------------------------------------------------------

@@ -76,7 +76,8 @@ class Model:
     def _embed(self, params, batch) -> Tuple[jax.Array, jax.Array]:
         cfg = self.cfg
         if cfg.embed_inputs:
-            x = jnp.take(params["embed"], batch["tokens"], axis=0)
+            with jax.named_scope(L.EMBED):
+                x = jnp.take(params["embed"], batch["tokens"], axis=0)
             bsz, s = batch["tokens"].shape
         else:
             x = batch["embeds"]
@@ -89,6 +90,7 @@ class Model:
             )
         return x, positions
 
+    @jax.named_scope(L.HEAD)
     def _head(self, params, x) -> jax.Array:
         cfg = self.cfg
         x = L.apply_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_kind)
@@ -117,7 +119,8 @@ class Model:
         frontends); cache_index scalar int32. Returns (logits (B, V), cache)."""
         cfg = self.cfg
         if cfg.embed_inputs:
-            x = jnp.take(params["embed"], tokens, axis=0)
+            with jax.named_scope(L.EMBED):
+                x = jnp.take(params["embed"], tokens, axis=0)
         else:
             x = tokens  # already embedded (B, 1, D)
         x, new_cache = T.decode_stack(params["stack"], cache, x, cache_index, cfg)

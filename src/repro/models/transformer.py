@@ -45,6 +45,11 @@ __all__ = [
 # single block
 # ---------------------------------------------------------------------------
 
+def _norm(x, p, cfg: ArchConfig):
+    with jax.named_scope(L.NORM):
+        return L.apply_norm(x, p, cfg.norm_eps, cfg.norm_kind)
+
+
 def _init_block(key, cfg: ArchConfig, kind: str, is_moe: bool, dtype):
     ks = jax.random.split(key, 4)
     p: Dict[str, Any] = {"norm1": L.init_norm(cfg.d_model, dtype, kind=cfg.norm_kind)}
@@ -82,23 +87,24 @@ def _apply_block(
             p, x, cfg, kind, is_moe,
             positions=positions, window=window, rope_theta=rope_theta,
         )
-    h = L.apply_norm(x, p["norm1"], cfg.norm_eps, cfg.norm_kind)
+    h = _norm(x, p["norm1"], cfg)
     if kind == "attn":
-        mix = A.attention_block(
-            p["mixer"], h, cfg, positions=positions, window=window,
-            rope_theta=rope_theta,
-        )
+        with jax.named_scope(L.ATTENTION):
+            x = x + A.attention_block(
+                p["mixer"], h, cfg, positions=positions, window=window,
+                rope_theta=rope_theta,
+            )
     else:
-        mix = S.mamba_block(p["mixer"], h, cfg)
-    x = x + mix
+        x = x + S.mamba_block(p["mixer"], h, cfg)
     aux = jnp.zeros((), jnp.float32)
     if cfg.family != "ssm":
-        h = L.apply_norm(x, p["norm2"], cfg.norm_eps, cfg.norm_kind)
-        if is_moe:
-            f, aux = M.moe_ffn(p["ffn"], h, cfg)
-        else:
-            f = L.mlp_apply(p["ffn"], h, cfg.mlp_kind)
-        x = x + f
+        h = _norm(x, p["norm2"], cfg)
+        with jax.named_scope(L.MLP):
+            if is_moe:
+                f, aux = M.moe_ffn(p["ffn"], h, cfg)
+            else:
+                f = L.mlp_apply(p["ffn"], h, cfg.mlp_kind)
+            x = x + f
     return x, aux
 
 
@@ -237,35 +243,34 @@ def init_decode_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype):
 
 def _decode_block(p, x, cache_slices, cache_index, cfg, kind, *, window, rope_theta):
     """One layer of single-token decode. Returns (x, new_cache_slices)."""
-    h = L.apply_norm(x, p["norm1"], cfg.norm_eps, cfg.norm_kind)
+    h = _norm(x, p["norm1"], cfg)
     if kind == "attn":
-        mix, (k_new, v_new) = A.decode_attention_block(
-            p["mixer"], h, (cache_slices["k"], cache_slices["v"]),
-            cache_index, cfg, window=window, rope_theta=rope_theta,
-        )
+        with jax.named_scope(L.ATTENTION):
+            mix, (k_new, v_new) = A.decode_attention_block(
+                p["mixer"], h, (cache_slices["k"], cache_slices["v"]),
+                cache_index, cfg, window=window, rope_theta=rope_theta,
+            )
+            x = x + mix
         new_cache = {"k": k_new, "v": v_new}
     else:
         mix, (ssm_new, conv_new) = S.decode_mamba_block(
             p["mixer"], h, (cache_slices["ssm"], cache_slices["conv"]), cfg
         )
+        x = x + mix
         new_cache = {"ssm": ssm_new, "conv": conv_new}
-    x = x + mix
-    if cfg.family != "ssm":
-        h = L.apply_norm(x, p["norm2"], cfg.norm_eps, cfg.norm_kind)
-        if cfg.layer_is_moe(0) and cfg.uniform_stack:
-            f, _ = M.moe_ffn(p["ffn"], h, cfg)
-        elif "ffn" in p:
-            if cfg.forward_mode == "graph":
+    if cfg.family != "ssm" and "ffn" in p:
+        h = _norm(x, p["norm2"], cfg)
+        with jax.named_scope(L.MLP):
+            if cfg.layer_is_moe(0) and cfg.uniform_stack:
+                x = x + M.moe_ffn(p["ffn"], h, cfg)[0]
+            elif cfg.forward_mode == "graph":
                 # Decode's graph half: mixers mutate caches eagerly, the
                 # dense FFN is captured (residual fused into its launch).
                 from repro.models import forward as F
 
-                f = F.graph_ffn(p["ffn"], h, cfg, residual=x)
-                return f, new_cache
-            f = L.mlp_apply(p["ffn"], h, cfg.mlp_kind)
-        else:
-            f = 0.0
-        x = x + f
+                x = F.graph_ffn(p["ffn"], h, cfg, residual=x)
+            else:
+                x = x + L.mlp_apply(p["ffn"], h, cfg.mlp_kind)
     return x, new_cache
 
 
@@ -299,12 +304,14 @@ def decode_stack(params, cache, x, cache_index, cfg: ArchConfig):
         for j in range(period):
             kind = cfg.layer_kind(j)
             sub = lp[f"sub{j}"]
-            hh = L.apply_norm(h, sub["norm1"], cfg.norm_eps, cfg.norm_kind)
+            hh = _norm(h, sub["norm1"], cfg)
             if kind == "attn":
-                mix, (kn, vn) = A.decode_attention_block(
-                    sub["mixer"], hh, (csl["k"], csl["v"]), cache_index, cfg,
-                    rope_theta=cfg.rope_theta,
-                )
+                with jax.named_scope(L.ATTENTION):
+                    mix, (kn, vn) = A.decode_attention_block(
+                        sub["mixer"], hh, (csl["k"], csl["v"]), cache_index,
+                        cfg, rope_theta=cfg.rope_theta,
+                    )
+                    h = h + mix
                 new_c["k"], new_c["v"] = kn, vn
             else:
                 mix, (sn, cn) = S.decode_mamba_block(
@@ -314,13 +321,14 @@ def decode_stack(params, cache, x, cache_index, cfg: ArchConfig):
                 new_c["ssm"] = new_c["ssm"].at[mi].set(sn)
                 new_c["conv"] = new_c["conv"].at[mi].set(cn)
                 mi += 1
-            h = h + mix
-            hh = L.apply_norm(h, sub["norm2"], cfg.norm_eps, cfg.norm_kind)
-            if cfg.layer_is_moe(j):
-                f, _ = M.moe_ffn(sub["ffn"], hh, cfg)
-            else:
-                f = L.mlp_apply(sub["ffn"], hh, cfg.mlp_kind)
-            h = h + f
+                h = h + mix
+            hh = _norm(h, sub["norm2"], cfg)
+            with jax.named_scope(L.MLP):
+                if cfg.layer_is_moe(j):
+                    f, _ = M.moe_ffn(sub["ffn"], hh, cfg)
+                else:
+                    f = L.mlp_apply(sub["ffn"], hh, cfg.mlp_kind)
+                h = h + f
         _scale.__exit__(None, None, None)
         return h, new_c
 
