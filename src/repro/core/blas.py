@@ -1081,9 +1081,24 @@ def _decode_attn_eligible(q, k, v, lo, hi):
 
 
 def _decode_attn_host(q, k, v, lo, hi):
+    # Grouped-query form: the G = Hq // Hkv query heads of a key/value head
+    # form one (G, D) block against that head's cache, so K and V are read
+    # as stored and never repeated to every query head (attention_math's
+    # repeat keeps prefill's q-head dim shardable; the decode cache is
+    # sharded by batch and sequence instead).  Float32 scores, softmax and
+    # accumulation, as attention_math computes them.
+    b, hq, _, d = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(b, hkv, hq // hkv, d).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k.astype(jnp.float32)) * d ** -0.5
     slots = jnp.arange(k.shape[2], dtype=jnp.int32)
     kv_valid = jnp.logical_and(slots >= lo, slots < hi)
-    return attention_math(q, k, v, causal=False, kv_mask=kv_valid)
+    s = jnp.where(kv_valid, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    # fully-masked rows contribute zeros (matches the kernel semantics)
+    p = jnp.where(jnp.max(s, axis=-1, keepdims=True) <= -1e30 * 0.5, 0.0, p)
+    out = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
+    return out.reshape(b, hq, 1, d).astype(q.dtype)
 
 
 def _decode_attn_pallas(q, k, v, lo, hi, *, interpret=False):
