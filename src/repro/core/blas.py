@@ -547,8 +547,20 @@ def _qkv_concat(x, wq, wk, wv, bq, bk, bv):
 
 
 def _qkv_host(x, wq, wk, wv, *, bq=None, bk=None, bv=None):
+    dn = (((x.ndim - 1,), (0,)), ((), ()))
+    if _tp_mesh_info() is not None:
+        # Under a model-parallel mesh wq may be split by column where
+        # wk/wv are not: a concatenated weight would move wq between the
+        # devices on every call, so project apart and join the outputs.
+        # On one device the concatenated weight is one GEMM, and faster
+        # (yi-6b's serve step on a v5e: about 1 ms of 37).
+        parts = []
+        for w, b in ((wq, bq), (wk, bk), (wv, bv)):
+            y = _accum_dot(x, w, dn, x.dtype)
+            parts.append(y if b is None else y + b.astype(y.dtype))
+        return jnp.concatenate(parts, axis=-1)
     w, b = _qkv_concat(x, wq, wk, wv, bq, bk, bv)
-    y = _accum_dot(x, w, (((x.ndim - 1,), (0,)), ((), ())), x.dtype)
+    y = _accum_dot(x, w, dn, x.dtype)
     return y if b is None else y + b.astype(y.dtype)
 
 

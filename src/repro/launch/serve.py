@@ -65,6 +65,19 @@ _span = jax.profiler.TraceAnnotation
 _batch_ids = itertools.count()
 
 
+def _bytes_per_device(shapes, shardings=None) -> int:
+    """Bytes one device holds of a pytree of ``ShapeDtypeStruct``s laid out
+    by ``shardings`` (a matching tree of ``NamedSharding``s; None: whole)."""
+    leaves = jax.tree_util.tree_leaves(shapes)
+    shards = ([None] * len(leaves) if shardings is None
+              else jax.tree_util.tree_leaves(shardings))
+    return sum(
+        int(np.prod(s.shard_shape(x.shape) if s else x.shape))
+        * jnp.dtype(x.dtype).itemsize
+        for x, s in zip(leaves, shards)
+    )
+
+
 def _run_prefill(serve_step, params, cache, prompts: List[List[int]]):
     """Prefill token-by-token through the decode path (correct for rolling
     caches and hybrid state; a fused prefill kernel is a perf option)."""
@@ -159,7 +172,7 @@ def serve_batch(
     max_prompt = max(len(p) for p in prompts)
     with _span("serve.batch", batch=next(_batch_ids), requests=bsz,
                prompt_len=max_prompt, new_tokens=max_new_tokens,
-               cache_len=cache_len):
+               cache_len=cache_len, chips=1 if mesh is None else mesh.size):
         model = build_model(cfg)
         if params is None:
             params = init_params(model, seed, mesh)
@@ -169,12 +182,15 @@ def serve_batch(
 
         step_kw = {}
         with _span("serve.compile"):
-            with _span("serve.compile.cache"):
+            cache_shapes = jax.eval_shape(new_cache)
+            c_shard = (None if mesh is None else
+                       named(mesh, cache_pspecs(cache_shapes, mesh)))
+            with _span("serve.compile.cache",
+                       bytes_per_device=_bytes_per_device(cache_shapes,
+                                                          c_shard)):
                 if mesh is None:
                     cache = jax.jit(new_cache)()
                 else:
-                    c_shard = named(
-                        mesh, cache_pspecs(jax.eval_shape(new_cache), mesh))
                     cache = jax.jit(new_cache, out_shardings=c_shard)()
                     p_shard = jax.tree_util.tree_map(lambda x: x.sharding,
                                                      params)

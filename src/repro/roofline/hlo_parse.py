@@ -37,11 +37,12 @@ _COLLECTIVES = (
     "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
     "collective-permute",
 )
-# op line: `%name = TYPE opcode(%a, %b, ...), attrs`
-_OPLINE_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*(?P<type>\([^)]*\)|[\w\[\],{}]+)\s+"
-    r"(?P<opcode>[\w\-]+)\((?P<operands>[^)]*)\)(?P<attrs>.*)$"
-)
+# op line: `%name = TYPE opcode(%a, %b, ...), attrs`.  TYPE may be a tuple
+# and carry TPU tiled layouts (`bf16[16,8192]{1,0:T(8,128)(2,1)}`), so the
+# type and the operand list are cut at balanced brackets, not by a regex.
+_OPHEAD_RE = re.compile(r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s*(?P<opcode>[\w\-]+)\(")
+_TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
 _COMP_HDR_RE = re.compile(
     r"^(?:ENTRY\s+)?%?(?P<name>[\w.\-]+)\s*\((?P<params>.*)\)\s*->.*\{"
 )
@@ -74,6 +75,39 @@ def _split_operands(operands: str) -> List[str]:
     if cur:
         parts.append("".join(cur).strip())
     return [p for p in parts if p]
+
+
+def _balanced_end(text: str, start: int, stop_at_space: bool) -> int:
+    """Index just past the bracketed run that starts at ``text[start]``:
+    the matching close of an opening ``(``, or, with ``stop_at_space``, the
+    first space outside brackets."""
+    depth = 0
+    for i in range(start, len(text)):
+        ch = text[i]
+        if ch in "[{(":
+            depth += 1
+        elif ch in "]})":
+            depth -= 1
+            if depth == 0 and not stop_at_space:
+                return i + 1
+        elif ch == " " and depth == 0 and stop_at_space:
+            return i
+    return len(text)
+
+
+def _parse_op(raw: str) -> Optional[Tuple[str, str, str, str, str]]:
+    """``(name, type, opcode, operands, attrs)`` of an op line, else None."""
+    head = _OPHEAD_RE.match(raw)
+    if not head:
+        return None
+    i = head.end()
+    j = _balanced_end(raw, i, stop_at_space=not raw.startswith("(", i))
+    op = _OPCODE_RE.match(raw, j)
+    if not op:
+        return None
+    k = _balanced_end(raw, op.end() - 1, stop_at_space=False)
+    return (head.group("name"), raw[i:j], op.group("opcode"),
+            raw[op.end():k - 1], raw[k:])
 
 
 def _operand_type(operand: str, shapes: Dict[str, str]) -> str:
@@ -112,7 +146,9 @@ class Comp:
     collective_bytes: float = 0.0
     collective_counts: Dict[str, int] = dataclasses.field(default_factory=dict)
     calls: List[str] = dataclasses.field(default_factory=list)
-    whiles: List[Tuple[str, str]] = dataclasses.field(default_factory=list)
+    # (condition, body, trip count the compiler states or None)
+    whiles: List[Tuple[str, str, Optional[int]]] = dataclasses.field(
+        default_factory=list)
     max_constant: int = 0
 
 
@@ -149,34 +185,36 @@ def _parse_computations(text: str) -> Dict[str, Comp]:
         if raw.startswith("}"):
             cur = None
             continue
-        m = _OPLINE_RE.match(raw)
-        if not m:
-            c = _CONST_RE.search(raw)
-            if c:
-                cur.max_constant = max(cur.max_constant, int(c.group(1)))
-            continue
-        name, type_str = m.group("name"), m.group("type")
-        opcode, attrs = m.group("opcode"), m.group("attrs")
-        shapes[name] = type_str
         c = _CONST_RE.search(raw)
         if c:
             cur.max_constant = max(cur.max_constant, int(c.group(1)))
+        m = _parse_op(raw)
+        if not m:
+            continue
+        name, type_str, opcode, operands, attrs = m
+        shapes[name] = type_str
 
-        for cal in _CALL_RE.findall(attrs):
-            cur.calls.append(cal)
+        # An async op's -start, -update and -done all name the wrapped
+        # computation: it runs once, so it is visited from the -start only.
+        if not opcode.endswith(("-update", "-done")):
+            cur.calls.extend(_CALL_RE.findall(attrs))
         w = _WHILE_RE.search(attrs)
         if opcode == "while" and w:
-            cur.whiles.append((w.group(1), w.group(2)))
+            trip = _TRIP_RE.search(attrs)
+            cur.whiles.append((w.group(1), w.group(2),
+                               int(trip.group(1)) if trip else None))
 
         _, out_bytes = _shape_elems_bytes(type_str)
-        base = opcode.replace("-start", "")
+        # An async collective counts once, by its -done, whose result is
+        # the collective's (a -start's tuple holds the operands too).
+        base = opcode[:-len("-done")] if opcode.endswith("-done") else opcode
         if base in _COLLECTIVES:
             cur.collective_bytes += out_bytes
             cur.collective_counts[base] = cur.collective_counts.get(base, 0) + 1
 
         if opcode == "dot":
             res_dims = _dims_of(type_str) or []
-            ops_list = _split_operands(m.group("operands"))
+            ops_list = _split_operands(operands)
             lhs_dims = (
                 _dims_of(_operand_type(ops_list[0], shapes)) if ops_list else None
             ) or []
@@ -193,7 +231,7 @@ def _parse_computations(text: str) -> Dict[str, Comp]:
 
         if opcode not in _SKIP_TRAFFIC_OPS and not opcode.endswith("-done"):
             tb = out_bytes
-            for operand in _split_operands(m.group("operands")):
+            for operand in _split_operands(operands):
                 _, ob = _shape_elems_bytes(_operand_type(operand, shapes))
                 tb += ob
             cur.traffic_bytes += tb
@@ -242,9 +280,9 @@ def analyze_module(text: str) -> ModuleCosts:
             counts[k] = counts.get(k, 0) + v * mult
         for cal in c.calls:
             visit(cal, mult)
-        for cond, body in c.whiles:
+        for cond, body, known in c.whiles:
             num_whiles += 1
-            trip = max(comps.get(cond, Comp(cond)).max_constant, 1)
+            trip = known or max(comps.get(cond, Comp(cond)).max_constant, 1)
             visit(body, mult * trip)
             visit(cond, mult)  # cond cost ~ trip times, negligible: once
         seen_stack.pop()

@@ -192,10 +192,12 @@ def batch_pspecs(batch_shapes, mesh: Mesh):
 
 
 def cache_pspecs(cache_shapes, mesh: Mesh):
-    """Decode caches. KV: (L, B, Hkv, S, hd) — batch over DP when divisible,
-    sequence over ``model`` (SP; partial-softmax decode), and over
-    (``data``+``model``) when the batch is too small to use DP (long_500k).
-    SSM state (L, B, H, N, P): heads over ``model``."""
+    """Decode caches. KV: (L, B, Hkv, S, hd) — batch over DP when divisible;
+    KV heads over ``model`` when it divides them, which matches the
+    column-split ``wq`` (each device attends with its own query heads, no
+    exchange); else sequence over ``model`` (SP; partial-softmax decode),
+    and over (``data``+``model``) when the batch is too small to use DP
+    (long_500k).  SSM state (L, B, H, N, P): heads over ``model``."""
     dp = dp_axes(mesh)
 
     def rule(path, leaf):
@@ -203,6 +205,8 @@ def cache_pspecs(cache_shapes, mesh: Mesh):
         shape = leaf.shape
         if name in ("k", "v") and len(shape) == 5:
             b_ax = _guard(mesh, shape[1], dp)
+            if b_ax is not None and _guard(mesh, shape[2], "model"):
+                return P(None, b_ax, "model", None, None)
             seq_axes = "model" if b_ax is not None else tuple(dp) + ("model",)
             return P(None, b_ax, None, _guard(mesh, shape[3], seq_axes), None)
         if name == "ssm" and len(shape) >= 5:

@@ -100,3 +100,44 @@ def test_roofline_terms_math():
     assert r.memory_s == pytest.approx(1.0)
     assert r.collective_s == pytest.approx(1.0)
     assert r.dominant in ("compute", "memory", "collective")
+
+
+def test_tpu_layouts_async_pairs_and_stated_trip_count():
+    """A module as the TPU compiler prints it: tiled layouts in the types,
+    tuple results, an async all-gather pair (counted once, by its -done),
+    an async wrapper whose three ops name one computation (run once), and
+    a loop whose trip count the compiler states in its backend config."""
+    hlo = """
+HloModule tpu
+%wrapped (p0: bf16[16,8]) -> bf16[16,8] {
+  %p0 = bf16[16,8]{1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %ar = bf16[16,8]{1,0:T(8,128)(2,1)} all-reduce(%p0), to_apply=%add
+}
+
+%cond (p: (s32[], bf16[16,8])) -> pred[] {
+  %c = s32[] constant(7)
+  ROOT %lt = pred[] compare(%i, %c), direction=LT
+}
+
+%body (p: (s32[], bf16[16,8])) -> (s32[], bf16[16,8]) {
+  %ar = bf16[16,8]{1,0:T(8,128)(2,1)S(1)} all-reduce(%y), channel_id=2, to_apply=%add
+  ROOT %t = (s32[]{:T(128)}, bf16[16,8]{1,0:T(8,128)(2,1)S(1)}) tuple(%i, %ar)
+}
+
+ENTRY %main (a: bf16[16,8]) -> bf16[16,32] {
+  %a = bf16[16,8]{1,0:T(8,128)(2,1)} parameter(0)
+  %ags = (bf16[16,8]{1,0:T(8,128)(2,1)}, bf16[16,32]{1,0:T(8,128)(2,1)}) all-gather-start(%a), dimensions={1}
+  %agd = bf16[16,32]{1,0:T(8,128)(2,1)} all-gather-done(%ags)
+  %as = ((bf16[16,8]{1,0}), bf16[16,8]{1,0}, u32[]{:S(2)}) async-start(%a), calls=%wrapped
+  %au = ((bf16[16,8]{1,0}), bf16[16,8]{1,0}, u32[]{:S(2)}) async-update(%as), calls=%wrapped
+  %ad = bf16[16,8]{1,0:T(8,128)(2,1)} async-done(%au), calls=%wrapped
+  %w = (s32[]{:T(128)}, bf16[16,8]{1,0:T(8,128)(2,1)S(1)}) while(%init), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"20"}}
+  ROOT %r = bf16[16,32]{1,0:T(8,128)(2,1)} copy(%agd)
+}
+"""
+    mc = analyze_module(hlo)
+    ar = 16 * 8 * 2
+    assert mc.collective_bytes == 16 * 32 * 2 + ar + 20 * ar
+    assert mc.collective_counts["all-gather"] == 1
+    assert mc.collective_counts["all-reduce"] == 1 + 20
+    assert mc.num_whiles == 1

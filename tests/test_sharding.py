@@ -95,6 +95,14 @@ def test_cache_specs_sp():
         {"k": jax.ShapeDtypeStruct((80, 128, 8, 32768, 128), jnp.bfloat16)}, _mesh()
     )
     assert specs["k"] == P(None, ("data",), None, "model", None)
+    # A model axis that divides the KV heads splits them, as it splits wq.
+    class TP4:
+        axis_names = ("data", "model")
+        shape = {"data": 1, "model": 4}
+
+    specs = cache_pspecs(
+        {"k": jax.ShapeDtypeStruct((20, 16, 8, 2048, 128), jnp.bfloat16)}, TP4())
+    assert specs["k"] == P(None, ("data",), "model", None, None)
 
 
 MINI_DRYRUN = textwrap.dedent(
@@ -205,9 +213,20 @@ TP_SERVE = textwrap.dedent(
     from repro.launch.steps import init_params
     from repro.models import build_model
 
+    import repro.launch.serve as serve
+
+    # The spans' stats, by span name, of the last call.
+    stats, span = {}, serve._span
+
+    def recording(name, **kw):
+        stats[name] = kw
+        return span(name, **kw)
+
+    serve._span = recording
     prompts = [[3, 1, 4, 1, 5, 9], [2, 7, 1, 8, 2, 8]]
     kw = dict(smoke=True, max_new_tokens=5, cache_len=16)
     one = serve_batch("yi-6b", prompts, **kw)
+    one_stats, stats = stats, {}
     mesh = jax.make_mesh((1, 4), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     params = init_params(build_model(get_arch("yi-6b").reduced()), 0, mesh)
@@ -218,6 +237,9 @@ TP_SERVE = textwrap.dedent(
         "wq_cols": wq.shape[-1],
         "same_tokens": bool((one.tokens == four.tokens).all()),
         "logit_err": float(np.abs(one.logits - four.logits).max()),
+        "chips": [st["serve.batch"]["chips"] for st in (one_stats, stats)],
+        "cache_bytes": [st["serve.compile.cache"]["bytes_per_device"]
+                        for st in (one_stats, stats)],
     }))
     """
 )
@@ -237,6 +259,9 @@ def test_serve_batch_on_model_mesh_matches_one_device():
     assert rec["wq_shards"] == [rec["wq_cols"] // 4] * 4, rec
     assert rec["same_tokens"], rec
     assert rec["logit_err"] < 1e-4, rec
+    # The spans say how many chips the batch ran on, and one chip's cache.
+    assert rec["chips"] == [1, 4], rec
+    assert rec["cache_bytes"][0] == 4 * rec["cache_bytes"][1] > 0, rec
 
 
 def test_tp_and_moe_shard_map_numerics():

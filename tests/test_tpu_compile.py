@@ -12,17 +12,20 @@ one process at a time may load the TPU library, and every pytest worker
 imports this file.
 """
 
+import dataclasses
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, PartitionSpec, SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.kernels import ops
 from repro.launch.steps import make_serve_step
 from repro.models import build_model
+from repro.sharding import cache_pspecs, named, param_pspecs
 
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -120,3 +123,39 @@ def test_yi6b_full_width_serve_step_compiles(one_chip):
     )
     assert 12.0e9 < param_bytes < 12.3e9   # bf16 weights, ~6.06B params
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_qwen2_72b_tp4_serve_step_fits(topo):
+    """One 20-layer stage of Qwen2-72B at its published widths (d_model
+    8192, GQA 64/8, head 128, QKV bias, SwiGLU 29568, vocab 152064, bf16),
+    tensor-parallel over the four chips of a v5e:2x2 as ``serve_batch(mesh=)``
+    compiles it: batch 16, 2048 slots.  Each chip's arguments and temporaries
+    fit its 16 GB, and no weight moves between chips (no all-to-all)."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    model = build_model(dataclasses.replace(get_arch("qwen2-72b"), num_layers=20))
+    b, cache_len = 16, 2048
+
+    pshape = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    cshape = jax.eval_shape(lambda: model.init_decode_cache(b, cache_len))
+    p_shard = named(mesh, param_pspecs(pshape, mesh))
+    c_shard = named(mesh, cache_pspecs(cshape, mesh))
+    rep = named(mesh, PartitionSpec())
+
+    def placed(shapes, shardings):
+        return jax.tree_util.tree_map(
+            lambda x, s: _spec(x.shape, x.dtype, s), shapes, shardings)
+
+    params = placed(pshape, p_shard)
+    with mesh:
+        compiled = jax.jit(
+            make_serve_step(model), donate_argnums=(1,),
+            in_shardings=(p_shard, c_shard, rep, rep),
+            out_shardings=(rep, c_shard),
+        ).lower(params, placed(cshape, c_shard), _spec((b, 1), jnp.int32, rep),
+                _spec((), jnp.int32, rep)).compile()
+    param_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params)
+    )
+    assert 40.0e9 < param_bytes < 40.2e9   # bf16, 20,045,119,488 params
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert "all-to-all" not in compiled.as_text()
