@@ -4,6 +4,9 @@ A deliberately small but real serving loop: requests arrive with prompts,
 are padded into a batch, prefilled (full forward building the cache via
 teacher-forced decode), then decoded token-by-token with greedy/temperature
 sampling.  The same ``serve_step`` is what the decode dry-run cells lower.
+A greedy batch picks on the device: its step also returns the argmax of its
+logits, the next step is sent with those tokens, and each token's logits come
+to the host while the device runs the step after it.
 
 ``serve_cluster`` scales the loop to the multi-PMCA engine with placement
 as a first-class concept: each batch's prefill is placed by the active
@@ -78,31 +81,56 @@ def _bytes_per_device(shapes, shardings=None) -> int:
     )
 
 
+def _pick_on_device(step):
+    """``step`` returning also the greedy pick of its logits: ``(logits,
+    tokens, cache)``, ``tokens`` the (B, 1) int32 argmax.  ``jnp.argmax``
+    takes the first of equal values, as NumPy's does over the same values in
+    float32 (exact for bfloat16), so the tokens are the host pick's."""
+
+    def serve_step(params, cache, tokens, cache_index):
+        logits, cache = step(params, cache, tokens, cache_index)
+        return (logits, jnp.argmax(logits, -1, keepdims=True).astype(jnp.int32),
+                cache)
+
+    return serve_step
+
+
 def _run_prefill(serve_step, params, cache, prompts: List[List[int]]):
     """Prefill token-by-token through the decode path (correct for rolling
-    caches and hybrid state; a fused prefill kernel is a perf option)."""
+    caches and hybrid state; a fused prefill kernel is a perf option).
+
+    Returns the last step's outputs but the cache, as a list (its logits,
+    and its tokens where the step picks), the cache and the seconds."""
     max_prompt = max(len(p) for p in prompts)
     t0 = time.perf_counter()
-    logits = None
+    head = None
     with _span("serve.prefill", steps=max_prompt):
         for t in range(max_prompt):
             # A fresh host buffer every step: the step may still be reading
             # the last one (on the CPU, jnp.asarray can alias numpy memory).
             tok = np.array([[p[t] if t < len(p) else 0] for p in prompts],
                            np.int32)
-            logits, cache = serve_step(
+            *head, cache = serve_step(
                 params, cache, jnp.asarray(tok), jnp.int32(t)
             )
         with _span("serve.prefill.wait"):
-            jax.block_until_ready(logits)
-    return logits, cache, time.perf_counter() - t0
+            jax.block_until_ready(head)
+    return head, cache, time.perf_counter() - t0
 
 
 def _run_decode(
-    serve_step, params, cache, logits, *, start_pos: int,
+    serve_step, params, cache, logits, tokens=None, *, start_pos: int,
     max_new_tokens: int, temperature: float, seed: int,
 ):
-    """Greedy/temperature sampling loop from a prefilled cache."""
+    """Greedy/temperature sampling loop from a prefilled cache.
+
+    With ``tokens`` (the device's pick of ``logits``, from a step made by
+    :func:`_pick_on_device`) the greedy loop of :func:`_decode_device_pick`
+    runs; without, the host copies, picks and sends each token."""
+    if tokens is not None:
+        return _decode_device_pick(serve_step, params, cache, logits, tokens,
+                                   start_pos=start_pos,
+                                   max_new_tokens=max_new_tokens)
     bsz = logits.shape[0]
     rng = np.random.default_rng(seed)
     out = np.zeros((bsz, max_new_tokens), np.int32)
@@ -139,6 +167,46 @@ def _run_decode(
     return out, seen, cache, time.perf_counter() - t0
 
 
+def _decode_device_pick(serve_step, params, cache, logits, tokens, *,
+                        start_pos: int, max_new_tokens: int):
+    """Greedy decode with the pick in the step.  Step ``i + 1`` is sent with
+    the tokens step ``i`` returned before the host reads step ``i``, so the
+    host waits for, copies and records each token while the device runs the
+    step after it.  The logits land in one float32 buffer as they come, and
+    the time returned ends when the last of them is there and the cache is
+    ready: nothing is left to read after it."""
+    bsz, vocab = logits.shape
+    out = np.zeros((bsz, max_new_tokens), np.int32)
+    copied = logits.nbytes  # device bytes each token's copy reads
+    t0 = time.perf_counter()
+    seen = np.empty((bsz, max_new_tokens, vocab), np.float32)
+    # The first step is sent before the loop; each token's dispatch sends
+    # the step after the one the device runs (none after the last token).
+    # A NumPy position goes to the device with the call, faster than a
+    # jnp scalar, which the call must then place.
+    nxt = (serve_step(params, cache, tokens, np.int32(start_pos))
+           if max_new_tokens else None)
+    for i in range(max_new_tokens):
+        with _span("serve.decode.wait"):
+            jax.block_until_ready((logits, tokens))
+        with _span("serve.decode.copy", bytes=copied):
+            seen[:, i] = np.asarray(logits)
+        with _span("serve.decode.pick"):
+            out[:, i] = np.asarray(tokens)[:, 0]
+        with _span("serve.decode.dispatch"):
+            logits, tokens, cache = nxt
+            if i + 1 < max_new_tokens:
+                # Both start for the host as soon as their step ends.
+                logits.copy_to_host_async()
+                tokens.copy_to_host_async()
+                nxt = serve_step(params, cache, tokens,
+                                 np.int32(start_pos + i + 1))
+    # The last step's cache; its logits and tokens are not read.
+    with _span("serve.decode.stack", bytes=0):
+        jax.block_until_ready(cache)
+    return out, seen, cache, time.perf_counter() - t0
+
+
 def serve_batch(
     arch: str,
     prompts: List[List[int]],
@@ -170,9 +238,12 @@ def serve_batch(
         raise ValueError("encoder-only arch has no decode step")
     bsz = len(prompts)
     max_prompt = max(len(p) for p in prompts)
+    # Greedy batches pick on the device (_decode_device_pick).
+    on_device = temperature == 0
     with _span("serve.batch", batch=next(_batch_ids), requests=bsz,
                prompt_len=max_prompt, new_tokens=max_new_tokens,
-               cache_len=cache_len, chips=1 if mesh is None else mesh.size):
+               cache_len=cache_len, chips=1 if mesh is None else mesh.size,
+               pick="device" if on_device else "host"):
         model = build_model(cfg)
         if params is None:
             params = init_params(model, seed, mesh)
@@ -195,23 +266,28 @@ def serve_batch(
                     p_shard = jax.tree_util.tree_map(lambda x: x.sharding,
                                                      params)
                     rep = named(mesh, jax.sharding.PartitionSpec())
+                    outs = ((rep, rep, c_shard) if on_device
+                            else (rep, c_shard))
                     step_kw = dict(in_shardings=(p_shard, c_shard, rep, rep),
-                                   out_shardings=(rep, c_shard))
+                                   out_shardings=outs)
             t0 = time.perf_counter()
             in_mesh = contextlib.nullcontext() if mesh is None else mesh
+            step = make_serve_step(model)
+            if on_device:
+                step = _pick_on_device(step)
             with _span("serve.compile.step"), in_mesh:
                 serve_step = jax.jit(
-                    make_serve_step(model), donate_argnums=(1,), **step_kw
+                    step, donate_argnums=(1,), **step_kw
                 ).lower(
                     params, cache, jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
                     jax.ShapeDtypeStruct((), jnp.int32),
                 ).compile()
             compile_s = time.perf_counter() - t0
 
-        logits, cache, prefill_s = _run_prefill(serve_step, params, cache,
-                                                prompts)
+        head, cache, prefill_s = _run_prefill(serve_step, params, cache,
+                                              prompts)
         out, seen, cache, decode_s = _run_decode(
-            serve_step, params, cache, logits, start_pos=max_prompt,
+            serve_step, params, cache, *head, start_pos=max_prompt,
             max_new_tokens=max_new_tokens, temperature=temperature, seed=seed,
         )
     return ServeResult(
@@ -326,7 +402,7 @@ def serve_cluster(
     per_device_s: Dict[int, float] = {}
     prefill_placements: List[int] = []
     handles: List[DeviceHandle] = []
-    sessions = []  # (logits, cache, prefill_s, max_prompt)
+    sessions = []  # (prefill outputs, cache, prefill_s, max_prompt)
 
     results: List[ServeResult] = []
     placements: List[int] = []
@@ -343,7 +419,7 @@ def serve_cluster(
             )
             prefill_placements.append(p_dev)
             with cluster.pin_device(p_dev):
-                logits, cache, prefill_s = _run_prefill(
+                head, cache, prefill_s = _run_prefill(
                     serve_step, params, cache, prompts
                 )
             per_device_s[p_dev] = per_device_s.get(p_dev, 0.0) + p_bd.offload_s
@@ -355,14 +431,14 @@ def serve_cluster(
                 cluster.unstage_handle(handle)
             handles.append(handle)
             sessions.append(
-                (logits, cache, prefill_s, max(len(p) for p in prompts))
+                (head, cache, prefill_s, max(len(p) for p in prompts))
             )
 
         cluster.sync()  # prefill barrier: decode starts after prefills retire
 
         # ---- round 2: handle-affine decode placement + execution --------
         for i, prompts in enumerate(request_batches):
-            logits, cache, prefill_s, max_prompt = sessions[i]
+            head, cache, prefill_s, max_prompt = sessions[i]
             handle = handles[i]
             d_cost = _decode_cost(
                 len(prompts), max_new_tokens, handle.nbytes, cfg
@@ -389,7 +465,7 @@ def serve_cluster(
                 d2d_s += move_s
             with cluster.pin_device(d_dev):
                 out, seen, cache, decode_s = _run_decode(
-                    serve_step, params, cache, logits, start_pos=max_prompt,
+                    serve_step, params, cache, *head, start_pos=max_prompt,
                     max_new_tokens=max_new_tokens, temperature=temperature,
                     seed=seed,
                 )

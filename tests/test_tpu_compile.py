@@ -23,6 +23,7 @@ from jax.sharding import Mesh, PartitionSpec, SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.kernels import ops
+from repro.launch.serve import _pick_on_device
 from repro.launch.steps import make_serve_step
 from repro.models import build_model
 from repro.sharding import cache_pspecs, named, param_pspecs
@@ -98,9 +99,9 @@ def test_flash_attention_s2048_compiles(one_chip):
 
 
 def test_yi6b_full_width_serve_step_compiles(one_chip):
-    """The decode step ``serve_batch`` runs at yi-6b's published widths:
-    32 layers, d_model 4096, GQA 32/4, vocab 64000, bf16, batch 4 and a
-    2048-slot cache."""
+    """The greedy decode step ``serve_batch`` runs (the argmax in the step)
+    at yi-6b's published widths: 32 layers, d_model 4096, GQA 32/4, vocab
+    64000, bf16, batch 4 and a 2048-slot cache."""
     model = build_model(get_arch("yi-6b"))
     b, cache_len = 4, 2048
 
@@ -116,7 +117,7 @@ def test_yi6b_full_width_serve_step_compiles(one_chip):
     tokens = _spec((b, 1), jnp.int32, one_chip)
     index = _spec((), jnp.int32, one_chip)
     compiled = jax.jit(
-        make_serve_step(model), donate_argnums=(1,)
+        _pick_on_device(make_serve_step(model)), donate_argnums=(1,)
     ).lower(params, cache, tokens, index).compile()
     param_bytes = sum(
         x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params)
@@ -128,9 +129,10 @@ def test_yi6b_full_width_serve_step_compiles(one_chip):
 def test_qwen2_72b_tp4_serve_step_fits(topo):
     """One 20-layer stage of Qwen2-72B at its published widths (d_model
     8192, GQA 64/8, head 128, QKV bias, SwiGLU 29568, vocab 152064, bf16),
-    tensor-parallel over the four chips of a v5e:2x2 as ``serve_batch(mesh=)``
-    compiles it: batch 16, 2048 slots.  Each chip's arguments and temporaries
-    fit its 16 GB, and no weight moves between chips (no all-to-all)."""
+    tensor-parallel over the four chips of a v5e:2x2 as a greedy
+    ``serve_batch(mesh=)`` compiles it: batch 16, 2048 slots.  Each chip's
+    arguments and temporaries fit its 16 GB, and no weight moves between
+    chips (no all-to-all)."""
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
     model = build_model(dataclasses.replace(get_arch("qwen2-72b"), num_layers=20))
     b, cache_len = 16, 2048
@@ -148,9 +150,9 @@ def test_qwen2_72b_tp4_serve_step_fits(topo):
     params = placed(pshape, p_shard)
     with mesh:
         compiled = jax.jit(
-            make_serve_step(model), donate_argnums=(1,),
+            _pick_on_device(make_serve_step(model)), donate_argnums=(1,),
             in_shardings=(p_shard, c_shard, rep, rep),
-            out_shardings=(rep, c_shard),
+            out_shardings=(rep, rep, c_shard),
         ).lower(params, placed(cshape, c_shard), _spec((b, 1), jnp.int32, rep),
                 _spec((), jnp.int32, rep)).compile()
     param_bytes = sum(
